@@ -5,10 +5,10 @@ against a committed baseline, matched BY ROW NAME. A row fails when
 
     fresh_us > PERF_GATE_FACTOR * baseline_us        (default factor 1.5)
 
-Rows named `total_wall_s` or `*/ERROR` and rows present on only one side
-are reported but never gated (suite composition may drift between the
-baseline and a smoke run; an ERROR row should fail its own CI step, not
-masquerade as a latency regression). The baseline's git sha + timestamp
+Rows named `total_wall_s` and rows present on only one side are reported
+but never gated (suite composition may drift between the baseline and a
+smoke run). A fresh `*/ERROR` row — a suite that raised — fails the gate
+outright. The baseline's git sha + timestamp
 stamps (benchmarks/run.py) are echoed so a gate failure names the exact
 commit it regressed against.
 
@@ -60,7 +60,9 @@ def gate(fresh: dict, baseline: dict, factor: float) -> list[str]:
     """-> list of human-readable failures (empty = gate green)."""
     f_rows, f_bad = _rows(fresh)
     b_rows, b_bad = _rows(baseline)
-    failures = []
+    failures = [f"{row['name']}: suite raised {row.get('derived', '')}"
+                for row in fresh.get("results", [])
+                if row.get("name", "").endswith("/ERROR")]
     for name in sorted(f_rows.keys() & b_rows.keys()):
         new, old = f_rows[name], b_rows[name]
         ratio = new / old

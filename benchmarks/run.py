@@ -11,6 +11,9 @@ Usage:
 --json   write all emitted rows to F (default ``BENCH_all.json`` at the
          repo root, or ``BENCH_<suite>.json`` when --only names exactly
          one suite) — the artifact CI uploads per run.
+
+A suite that raises is recorded as a ``<suite>/ERROR`` row, the other
+suites still run, and the run then exits 1.
 """
 from __future__ import annotations
 
@@ -29,23 +32,15 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _apply_xla_flags() -> None:
     """The olmax runner recipe (SNIPPETS.md): quiet the TF logging spew
     and pin the host-platform device count before jax initializes its
-    backend. `--xla_step_marker_location=1` (step marker on the outer
-    while loop — what profilers key trace slices on) is applied only
-    when a TPU runtime is present: XLA on CPU hosts aborts at startup on
-    that flag. TPU presence means actual hardware (/dev/accel* device
-    nodes, the TPU-VM contract) or an explicit JAX_PLATFORMS=tpu — NOT
-    merely an installed libtpu wheel, which CPU test containers carry
-    too. Flags the caller already set in $XLA_FLAGS win.
+    backend. Flags the caller already set in $XLA_FLAGS win. The recipe's
+    `--xla_step_marker_location` flag is left out: the installed XLA
+    refuses it at start-up on the TPU as well as on the CPU.
 
     Called from the __main__ entry only: in-process callers of `main()`
     (tests, notebooks) keep their environment untouched — mutating
     $XLA_FLAGS mid-process would leak into any subprocess they spawn."""
     os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "4")
     flags = ["--xla_force_host_platform_device_count=1"]
-    on_tpu = ("tpu" in os.environ.get("JAX_PLATFORMS", "").lower()
-              or any(os.path.exists(f"/dev/accel{i}") for i in range(8)))
-    if on_tpu:
-        flags.append("--xla_step_marker_location=1")
     existing = os.environ.get("XLA_FLAGS", "")
     extra = " ".join(f for f in flags if f.split("=")[0] not in existing)
     if extra:
@@ -62,7 +57,8 @@ def _git_sha() -> str | None:
         return None
 
 
-def main(argv: list[str] | None = None) -> None:
+def main(argv: list[str] | None = None) -> int:
+    """-> exit code: 1 when any suite raised, else 0."""
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("only", nargs="?", default=None,
                         help="legacy positional form of --only")
@@ -76,6 +72,7 @@ def main(argv: list[str] | None = None) -> None:
     args = parser.parse_args(argv)
 
     rows: list[dict] = []
+    failed: list[str] = []
 
     def _emit(name: str, us: float, derived: str = "") -> None:
         print(f"{name},{us:.1f},{derived}", flush=True)
@@ -127,8 +124,9 @@ def main(argv: list[str] | None = None) -> None:
             kwargs["smoke"] = True
         try:
             fn(_emit, **kwargs)
-        except Exception as e:  # keep the harness running; report
+        except Exception as e:  # run the other suites, then fail
             _emit(f"{name}/ERROR", 0.0, f"{type(e).__name__}: {e}")
+            failed.append(name)
     _emit("total_wall_s", (time.time() - t0) * 1e6, "")
 
     if args.json is not None:
@@ -152,8 +150,14 @@ def main(argv: list[str] | None = None) -> None:
             json.dump(record, f, indent=2)
             f.write("\n")
         print(f"wrote {path}", flush=True)
+    if failed:
+        print(f"suite(s) failed: {', '.join(failed)}", flush=True)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
     _apply_xla_flags()   # process entry: before jax initializes
-    main()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    raise SystemExit(main())

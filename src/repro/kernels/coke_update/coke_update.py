@@ -60,7 +60,7 @@ def _coke_kernel(theta_ref, hat_ref, gamma_ref, grad_ref, left_ref,
     gaug = g + 2.0 * rho * deg * th + gm - rho * (deg * hat + l + r)
     gaug_ref[...] = gaug.astype(gaug_ref.dtype)
     diff = hat - th
-    xisq_ref[0, 0] = jnp.sum(diff * diff)
+    xisq_ref[...] = jnp.sum(diff * diff, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("rho", "deg", "block_d",
@@ -71,28 +71,30 @@ def _coke_fused_update(theta, theta_hat, gamma, grad, left, right, *,
     N, D = theta.shape
     bd = min(block_d, D)
     pad = (-D) % bd
-    if pad:
-        padf = lambda a: jnp.pad(a, ((0, 0), (0, pad)))
-        theta, theta_hat, gamma, grad, left, right = map(
-            padf, (theta, theta_hat, gamma, grad, left, right))
     Dp = D + pad
     nblocks = Dp // bd
+    # rows travel as (N, 1, Dp) with the agent dim squeezed out of the
+    # block: a (1, bd) block then spans the whole second-minor dim, which
+    # Mosaic's (8, 128)-or-full-dim tiling rule admits (a (1, bd) block
+    # of an (N, Dp) array does not)
+    rows = lambda a: jnp.pad(a, ((0, 0), (0, pad)))[:, None, :]
+    row_spec = pl.BlockSpec((None, 1, bd), lambda i, j: (i, 0, j))
 
     gaug, xisq = pl.pallas_call(
         functools.partial(_coke_kernel, rho=rho, deg=deg),
         grid=(N, nblocks),
-        in_specs=[pl.BlockSpec((1, bd), lambda i, j: (i, j))] * 6,
+        in_specs=[row_spec] * 6,
         out_specs=[
-            pl.BlockSpec((1, bd), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            row_spec,
+            pl.BlockSpec((None, None, 1, 1), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, Dp), jnp.float32),
-            jax.ShapeDtypeStruct((N, nblocks), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1, Dp), jnp.float32),
+            jax.ShapeDtypeStruct((N, nblocks, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(theta, theta_hat, gamma, grad, left, right)
-    return gaug[:, :D], jnp.sum(xisq, axis=1)
+    )(*map(rows, (theta, theta_hat, gamma, grad, left, right)))
+    return gaug[:, 0, :D], jnp.sum(xisq[:, :, 0, 0], axis=1)
 
 
 def coke_fused_update(theta: jax.Array, theta_hat: jax.Array,
@@ -189,9 +191,9 @@ def _megastep_kernel(*refs, n_nbr: int, nt: int, rho: float, deg: float,
         g_scr[...] = jnp.zeros_like(g_scr)
 
     th = theta_ref[...].astype(jnp.float32)          # (1, Dp), VMEM-resident
-    phi = phi_ref[0].astype(jnp.float32)             # (bt, Dp) streamed tile
+    phi = phi_ref[...].astype(jnp.float32)           # (bt, Dp) streamed tile
     r = jnp.dot(phi, th.T, preferred_element_type=jnp.float32)    # (bt, 1)
-    resid = r - y_ref[...].astype(jnp.float32).T
+    resid = r - y_ref[...].astype(jnp.float32)                    # (bt, 1)
     g_scr[...] += jnp.dot(resid.T, phi, preferred_element_type=jnp.float32)
 
     @pl.when(t == nt - 1)
@@ -206,7 +208,7 @@ def _megastep_kernel(*refs, n_nbr: int, nt: int, rho: float, deg: float,
         theta_new = th - lr * gaug
         out_ref[...] = theta_new
         d = theta_new - hat
-        xisq_ref[0, 0] = jnp.sum(d * d)
+        xisq_ref[...] = jnp.sum(d * d, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("rho", "lam", "lr", "offsets",
@@ -221,48 +223,49 @@ def _coke_megastep(theta, theta_hat, gamma, phi, y, *, rho, lam, lr,
     sc = megastep_scalars(rho=rho, lam=lam, lr=lr, n_agents=N, n_samples=T,
                           n_offsets=len(offsets))
 
+    # Every operand carries the agent dim as a squeezed leading block dim
+    # (`None`), so each block's last two dims either span the whole array
+    # dim or are (8, 128)-aligned — the tiling rule Mosaic enforces:
+    # per-agent rows are (N, 1, Dp), labels (N, Tp, 1), xi_sq (N, 1, 1).
     pad_row = lambda a: jnp.pad(a.astype(jnp.float32),
-                                ((0, 0), (0, Dp - D)))
+                                ((0, 0), (0, Dp - D)))[:, None, :]
     theta, theta_hat, gamma = map(pad_row, (theta, theta_hat, gamma))
     phi = jnp.pad(phi.astype(jnp.float32),
                   ((0, 0), (0, Tp - T), (0, Dp - D)))
-    y = jnp.pad(y.astype(jnp.float32), ((0, 0), (0, Tp - T)))
+    y = jnp.pad(y.astype(jnp.float32), ((0, 0), (0, Tp - T)))[:, :, None]
 
-    row_spec = pl.BlockSpec((1, Dp), lambda i, t: (i, 0))
+    row_spec = pl.BlockSpec((None, 1, Dp), lambda i, t: (i, 0, 0))
     nbr_specs = []
     for o in offsets:
-        nbr_specs.append(
-            pl.BlockSpec((1, Dp), lambda i, t, o=o: ((i + o) % N, 0)))
-        nbr_specs.append(
-            pl.BlockSpec((1, Dp), lambda i, t, o=o: ((i - o) % N, 0)))
+        nbr_specs.append(pl.BlockSpec(
+            (None, 1, Dp), lambda i, t, o=o: ((i + o) % N, 0, 0)))
+        nbr_specs.append(pl.BlockSpec(
+            (None, 1, Dp), lambda i, t, o=o: ((i - o) % N, 0, 0)))
 
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"))
     theta_new, xisq = pl.pallas_call(
         functools.partial(_megastep_kernel, n_nbr=n_nbr, nt=nt, **sc),
         grid=(N, nt),
         in_specs=[row_spec, row_spec, row_spec, *nbr_specs,
-                  pl.BlockSpec((1, bt, Dp), lambda i, t: (i, t, 0)),
-                  pl.BlockSpec((1, bt), lambda i, t: (i, t))],
+                  pl.BlockSpec((None, bt, Dp), lambda i, t: (i, t, 0)),
+                  pl.BlockSpec((None, bt, 1), lambda i, t: (i, t, 0))],
         out_specs=[
-            pl.BlockSpec((1, Dp), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
+            row_spec,
+            pl.BlockSpec((None, 1, 1), lambda i, t: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, Dp), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1, Dp), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, Dp), jnp.float32)],
         input_output_aliases={0: 0},
         cost_estimate=pl.CostEstimate(
-            flops=lp.cost["flops"], transcendentals=0,
+            flops=int(lp.cost["flops"]), transcendentals=0,
             bytes_accessed=int(lp.cost["bytes accessed"])),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(theta, theta_hat, gamma, *([theta_hat] * n_nbr), phi, y)
-    return theta_new[:, :D], xisq[:, 0]
+    return theta_new[:, 0, :D], xisq[:, 0, 0]
 
 
 def coke_megastep(theta: jax.Array, theta_hat: jax.Array, gamma: jax.Array,

@@ -9,7 +9,7 @@ experiment, so this is the paper workload's compute hot spot.
 
 Tiling: grid (T/bt, L/bl); X tile (bt, d) with d kept whole (assigned
 datasets have d <= 96; the wrapper pads d to a lane multiple), Omega tile
-(d, bl), bias tile (bl,), out tile (bt, bl). bt/bl default to MXU-aligned
+(d, bl), bias tile (1, bl), out tile (bt, bl). bt/bl default to MXU-aligned
 128.
 """
 from __future__ import annotations
@@ -26,7 +26,7 @@ from repro.kernels.runtime import resolve_interpret
 def _rff_kernel(x_ref, omega_ref, bias_ref, out_ref, *, scale: float):
     proj = jnp.dot(x_ref[...], omega_ref[...],
                    preferred_element_type=jnp.float32)
-    out_ref[...] = (scale * jnp.cos(proj + bias_ref[...][None, :])
+    out_ref[...] = (scale * jnp.cos(proj + bias_ref[...])
                     ).astype(out_ref.dtype)
 
 
@@ -44,7 +44,9 @@ def _rff_pallas(x: jax.Array, omega: jax.Array, bias: jax.Array,
     pad_d = (-d) % 8  # sublane alignment for the contracted dim
     xp = jnp.pad(x, ((0, pad_t), (0, pad_d)))
     op = jnp.pad(omega, ((0, pad_d), (0, pad_l)))
-    bp = jnp.pad(bias, (0, pad_l))
+    # the bias travels as a (1, L) row: a 1-D (bl,) block gets a layout
+    # XLA and Mosaic disagree on, a (1, bl) block spans the full row dim
+    bp = jnp.pad(bias, (0, pad_l))[None, :]
     Tp, dp = xp.shape
     Lp = op.shape[1]
 
@@ -54,7 +56,7 @@ def _rff_pallas(x: jax.Array, omega: jax.Array, bias: jax.Array,
         in_specs=[
             pl.BlockSpec((bt, dp), lambda i, j: (i, 0)),
             pl.BlockSpec((dp, bl), lambda i, j: (0, j)),
-            pl.BlockSpec((bl,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bl), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bt, bl), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Tp, Lp), x.dtype),

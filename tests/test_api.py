@@ -223,11 +223,16 @@ def test_chunk_boundary_parity_bit_identical(built):
 
 
 def test_oracle_distance_recorded_and_shrinks(built):
-    r = fit(BASE.replace(algorithm="dkla", num_iters=600,
+    """DKLA converges linearly to the ridge oracle (Thm 1). On this problem
+    the distance falls by a factor of about 0.80 per 100 iterations, so it
+    crosses 0.2 * d[0] near iteration 610 (0.208 at 600): 800 iterations
+    put the 0.2 bound at a measured 0.13. It also falls at every step."""
+    r = fit(BASE.replace(algorithm="dkla", num_iters=800,
                          record_oracle_distance=True),
             problem=built.problem)
-    d = r.history["dist_to_oracle"]
-    assert d.shape == (600,)
+    d = np.asarray(r.history["dist_to_oracle"])
+    assert d.shape == (800,)
+    assert np.all(np.diff(d) < 0)
     assert float(d[-1]) < 0.2 * float(d[0])
 
 

@@ -49,8 +49,8 @@ def test_unknown_suite_rejected(capsys):
 def test_xla_flags_recipe(monkeypatch):
     """Caller-set flags win (no duplicate device-count flag — XLA takes
     the LAST occurrence, which would silently override the caller), the
-    TPU-only step-marker flag is never added on a CPU host (XLA aborts
-    at startup on it), and the TF log level quiets by default."""
+    step-marker flag is never added (XLA aborts at startup on it, on the
+    TPU as on the CPU), and the TF log level quiets by default."""
     monkeypatch.setenv("XLA_FLAGS",
                        "--xla_force_host_platform_device_count=8")
     monkeypatch.delenv("TF_CPP_MIN_LOG_LEVEL", raising=False)
@@ -59,8 +59,7 @@ def test_xla_flags_recipe(monkeypatch):
     flags = os.environ["XLA_FLAGS"]
     assert flags.count("--xla_force_host_platform_device_count") == 1
     assert "device_count=8" in flags
-    if not os.path.exists("/dev/accel0"):  # the suite's CPU containers
-        assert "--xla_step_marker_location" not in flags
+    assert "--xla_step_marker_location" not in flags
     assert os.environ["TF_CPP_MIN_LOG_LEVEL"] == "4"
 
 
@@ -74,6 +73,62 @@ def test_main_does_not_mutate_process_env(monkeypatch, capsys):
         bench_run.main(["--only", "nope"])
     capsys.readouterr()
     assert "XLA_FLAGS" not in os.environ
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"],
+                         ids=["unset", "set"])
+def test_compile_cache_location(monkeypatch, env_dir):
+    """The entry points' persistent compile cache: with
+    $JAX_COMPILATION_CACHE_DIR set, JAX reads it and nothing is set in
+    code; unset, the cache lives at the one fixed, git-ignored directory
+    in the checkout."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        used = compile_cache.enable_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_dir is None:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert used == after == os.path.join(root, ".jax_cache")
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    else:
+        assert used == env_dir and after == before
+
+
+def test_failed_suite_fails_the_run(monkeypatch, capsys):
+    """A suite that raises becomes a `<suite>/ERROR` row and the run
+    returns exit code 1 instead of passing."""
+    from benchmarks import roofline
+
+    def boom(emit, **kw):
+        raise RuntimeError("suite crashed")
+
+    monkeypatch.setattr(roofline, "main", boom)
+    assert bench_run.main(["--only", "roofline"]) == 1
+    out = capsys.readouterr().out
+    assert "roofline/ERROR" in out and "suite crashed" in out
+
+
+def test_gate_fails_on_fresh_error_row(capsys):
+    """A fresh ERROR row fails the gate even when every timed row is
+    within the limit."""
+    fresh = {"results": [{"name": "a", "us_per_call": 10.0},
+                         {"name": "fused/ERROR", "us_per_call": 0.0,
+                          "derived": "ValueError: boom"}]}
+    base = {"results": [{"name": "a", "us_per_call": 10.0}]}
+    failures = perf_gate.gate(fresh, base, 1.5)
+    assert failures == ["fused/ERROR: suite raised ValueError: boom"]
+    capsys.readouterr()
 
 
 # ---------------------------------------------------------------------------

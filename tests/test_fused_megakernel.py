@@ -8,6 +8,7 @@ resolver contract (`repro.kernels.runtime.resolve_interpret`)."""
 import dataclasses
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -198,9 +199,9 @@ def test_megakernel_bitwise_vs_unfused_stepprogram(name, monkeypatch):
 
 def _count_pallas_calls(jaxpr) -> int:
     def subs(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             return [v.jaxpr]
-        if isinstance(v, jax.core.Jaxpr):
+        if isinstance(v, jex_core.Jaxpr):
             return [v]
         if isinstance(v, (tuple, list)):
             return [j for x in v for j in subs(x)]

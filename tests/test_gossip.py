@@ -241,8 +241,8 @@ def test_grow_take_agents_roundtrip():
 # ---------------------------------------------------------------------------
 
 def test_quarter_participation_converges_n200():
-    """gossip at participation=0.25 on N=200 reaches within 2x of the sync
-    final train-MSE. Gossip gets 4x the rounds — equal EXPECTED per-agent
+    """gossip at participation=0.25 on N=200 reaches within 2.5x of the
+    sync final train-MSE. Gossip gets 4x the rounds — equal EXPECTED per-agent
     work — which is the standard partial-participation accounting (each
     tick updates ~N/4 agents)."""
     cfg = FitConfig(
@@ -256,7 +256,15 @@ def test_quarter_participation_converges_n200():
                           num_iters=400), problem=problem)
     sync_mse = float(sync.history["train_mse"][-1])
     gsp_mse = float(gsp.history["train_mse"][-1])
-    assert gsp_mse <= 2.0 * sync_mse, (gsp_mse, sync_mse)
+    # Gossip settles within ~50 rounds at a level set by the participation
+    # draw: over data seeds 0-5 its final MSE is 1.40-2.10x sync's (2.10 is
+    # this seed; the draws come from JAX's threefry stream, so they moved
+    # with the JAX release). 2.5x sits above that spread. A run whose
+    # duals made no progress stays near its first-round MSE (~0.41 here,
+    # ~35x sync), which the second bound rules out directly: at least a
+    # 10x drop (16x measured).
+    assert gsp_mse <= 2.5 * sync_mse, (gsp_mse, sync_mse)
+    assert gsp_mse <= 0.1 * float(gsp.history["train_mse"][0])
     # sampling holds per-round traffic to ~N/4: across 4x the rounds the
     # total transmission count stays under 4x sync's censored total (and
     # far under the 400 * 200 full-broadcast count)

@@ -40,13 +40,20 @@ def variant(base: KernelModel, i: int) -> KernelModel:
     return dataclasses.replace(base, theta=jnp.asarray(theta), thetas=None)
 
 
-def rowwise_ref(model: KernelModel, x: np.ndarray,
-                theta) -> np.ndarray:
+def rowwise_ref(model: KernelModel, x: np.ndarray, theta,
+                bucket: int | None = None) -> np.ndarray:
     """The bit-level serving reference: score_rows with x's rows all tagged
-    to one theta."""
+    to one theta. With `bucket`, x is zero-padded to that many rows first,
+    so the reference runs at the same batch shape as the server's device
+    call: XLA's CPU backend vectorizes the length-D row reduction
+    differently below 128 rows than at 128 and above, and a 2-row
+    score_rows then differs from a 32-row bucket by 1 ulp in some rows."""
+    b = x.shape[0]
+    if bucket is not None:
+        x = np.concatenate([x, np.zeros((bucket - b, x.shape[1]), x.dtype)])
     rows = np.broadcast_to(np.asarray(theta),
                            (x.shape[0], model.num_features))
-    return np.asarray(model.score_rows(x, rows))
+    return np.asarray(model.score_rows(x, rows))[:b]
 
 
 @pytest.fixture(scope="module")
@@ -226,12 +233,14 @@ def test_multi_tenant_gather_parity_under_paging(base_model, registry8,
     """Tagged requests through a store FORCED smaller than the tenant set:
     every answer is bit-identical to the row-wise reference with that
     tenant's registry theta, and within reduction-order of its
-    `KernelModel.predict`."""
+    `KernelModel.predict`. One bucket shape, so the reference can run at
+    the server's own batch shape (see `rowwise_ref`)."""
     rng = np.random.default_rng(3)
     server = KernelServer(
         model=base_model, registry=registry8,
         store=ThetaStore(4, base_model.num_features),
-        config=KernelServeConfig(max_delay_ms=5.0), autostart=False)
+        config=KernelServeConfig(max_delay_ms=5.0, max_batch=128,
+                                 buckets=(128,)), autostart=False)
     reqs = []
     for i in range(20):
         mid = f"user-{rng.integers(0, 8)}"
@@ -244,7 +253,8 @@ def test_multi_tenant_gather_parity_under_paging(base_model, registry8,
     assert server.stats()["store"]["faults"] > 0  # paging actually happened
     for mid, x, out in outs:
         theta = registry8.load(mid).theta
-        np.testing.assert_array_equal(out, rowwise_ref(base_model, x, theta))
+        np.testing.assert_array_equal(
+            out, rowwise_ref(base_model, x, theta, bucket=128))
         np.testing.assert_allclose(
             out, np.asarray(registry8.load(mid).predict(x)), atol=2e-6)
 
@@ -252,7 +262,8 @@ def test_multi_tenant_gather_parity_under_paging(base_model, registry8,
 def test_thousand_resident_models_bit_parity(base_model, queries):
     """The acceptance-scale contract: one server, >= 1000 resident models
     in one (M, D) stack, every tagged answer bit-identical to its model's
-    row-wise reference — through bucket-padded gathered device calls."""
+    row-wise reference — through bucket-padded gathered device calls, all
+    of one bucket shape so the reference runs at the same batch shape."""
     M, D = 1000, base_model.num_features
     rng = np.random.default_rng(11)
     thetas = rng.normal(scale=0.2, size=(M, D)).astype(np.float32)
@@ -260,7 +271,9 @@ def test_thousand_resident_models_bit_parity(base_model, queries):
     store = ThetaStore(1024, D)
     store.put_many(ids, thetas)
     server = KernelServer(model=base_model, store=store,
-                          config=KernelServeConfig(max_delay_ms=5.0),
+                          config=KernelServeConfig(max_delay_ms=5.0,
+                                                   max_batch=128,
+                                                   buckets=(128,)),
                           autostart=False)
     assert len(store) >= 1000
     picks = rng.integers(0, M, size=100)
@@ -271,8 +284,8 @@ def test_thousand_resident_models_bit_parity(base_model, queries):
     server.stop()
     for j, (i, out) in enumerate(zip(picks, outs)):
         x = queries[j % 32:j % 32 + 2]
-        np.testing.assert_array_equal(out,
-                                      rowwise_ref(base_model, x, thetas[i]))
+        np.testing.assert_array_equal(
+            out, rowwise_ref(base_model, x, thetas[i], bucket=128))
 
 
 def test_answer_independent_of_cobatched_tenants(base_model, registry8,
