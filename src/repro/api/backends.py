@@ -199,17 +199,19 @@ def _consensus_chunk(problem, params, cstate, oracle, comm, gossip,
             adjacency=adjacency, alive=alive, joined=joined)
         if personalize is not None:
             cstate = dict(cstate, adjacency=adjacency)
-        bits = extra.get("bits")
-        if bits is None:  # policy-unaware strategy (cta): full precision
-            bits = _uncompressed_bits(problem, cstate["comms"])
-        m = _stacked_metrics(problem, params["theta"], cstate["comms"],
-                             bits)
-        m.update(extra)
-        if pz_metric:  # key-parity with the simulator personalized path
-            m["per_agent_mse"] = _per_agent_mse(problem, params["theta"])
-        if oracle is not None:
-            m["dist_to_oracle"] = jnp.max(jnp.linalg.norm(
-                params["theta"] - oracle, axis=-1))
+        with jax.named_scope("coke.history"):
+            bits = extra.get("bits")
+            if bits is None:  # policy-unaware strategy (cta): full precision
+                bits = _uncompressed_bits(problem, cstate["comms"])
+            m = _stacked_metrics(problem, params["theta"], cstate["comms"],
+                                 bits)
+            m.update(extra)
+            if pz_metric:  # key-parity with the simulator personalized path
+                m["per_agent_mse"] = _per_agent_mse(problem,
+                                                    params["theta"])
+            if oracle is not None:
+                m["dist_to_oracle"] = jnp.max(jnp.linalg.norm(
+                    params["theta"] - oracle, axis=-1))
         return (params, cstate), m
 
     (params, cstate), hist = jax.lax.scan(body, (params, cstate), None,
@@ -288,14 +290,15 @@ def _megastep_chunk(problem, params, cstate, oracle, comm, gossip, ccfg,
         # bitwise sgd); keep the carried slot's step count in sync
         if isinstance(opt, dict) and "count" in opt:
             opt = dict(opt, count=opt["count"] + 1)
-        bits = jnp.sum(new_st.comm.bits)
-        m = _stacked_metrics(problem, new_st.theta, new_st.comms, bits)
-        m["send_frac"] = ((new_st.comms - st.comms).astype(jnp.float32)
-                          / n_agents)
-        m["bits"] = bits
-        if oracle is not None:
-            m["dist_to_oracle"] = jnp.max(jnp.linalg.norm(
-                new_st.theta - oracle, axis=-1))
+        with jax.named_scope("coke.history"):
+            bits = jnp.sum(new_st.comm.bits)
+            m = _stacked_metrics(problem, new_st.theta, new_st.comms, bits)
+            m["send_frac"] = ((new_st.comms - st.comms).astype(jnp.float32)
+                              / n_agents)
+            m["bits"] = bits
+            if oracle is not None:
+                m["dist_to_oracle"] = jnp.max(jnp.linalg.norm(
+                    new_st.theta - oracle, axis=-1))
         return (new_st, opt), m
 
     st0 = _FusedCarry(

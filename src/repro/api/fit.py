@@ -42,10 +42,11 @@ def _simulator_chunk(solver: Solver, problem: Problem, ctx: SolveContext,
 
     def body(state, _):
         state = solver.step(problem, ctx, aux, state)
-        m = solver.metrics(problem, ctx, aux, state)
-        if oracle is not None:
-            m["dist_to_oracle"] = jnp.max(jnp.linalg.norm(
-                solver.theta_of(state) - oracle, axis=-1))
+        with jax.named_scope("coke.history"):
+            m = solver.metrics(problem, ctx, aux, state)
+            if oracle is not None:
+                m["dist_to_oracle"] = jnp.max(jnp.linalg.norm(
+                    solver.theta_of(state) - oracle, axis=-1))
         return state, m
 
     return jax.lax.scan(body, state, None, length=num_iters)
@@ -76,7 +77,9 @@ def _chunked_scan(chunk_fn, carry, num_iters: int, chunk_size: int | None,
     while True:
         n = num_iters - done if chunk_size is None else min(
             chunk_size, num_iters - done)
-        carry, h = chunk_fn(carry, n)  # n == 0 still yields (0,)-histories
+        with jax.profiler.TraceAnnotation("repro.fit.chunk"):
+            # n == 0 still yields (0,)-histories
+            carry, h = chunk_fn(carry, n)
         done += n
         hists.append(h)
         if progress_cb is not None and n > 0:
@@ -174,28 +177,11 @@ def _phased_runner(make_runner, plan):
     return runners[0][0], chunk_fn, runners[-1][2]
 
 
-def fit(config: FitConfig, problem: Problem | None = None, *,
-        progress_cb: ProgressCb | None = None,
-        oracle: jax.Array | None = None,
-        mesh=None) -> FitResult:
-    """Run `config.algorithm` on `config.backend` and record the paper's
-    evaluation trajectories.
-
-    problem     — an existing `admm.Problem`; None builds one from
-                  config.krr / config.graph (see repro.api.build_problem).
-    progress_cb — called as progress_cb(iters_done, last_metrics) after
-                  every `config.chunk_size` iterations.
-    oracle      — theta* (D,) for per-iteration distance-to-oracle; computed
-                  via the closed form when `config.record_oracle_distance`
-                  is set and no oracle is passed.
-    mesh        — optional jax mesh for the big-D path: the problem's
-                  feature dim shards over the mesh's "model" axis and the
-                  agent dim over its batch axes (theta/theta_hat/gamma live
-                  as (N, D/shards) per device; see
-                  distributed.sharding.feature_spec). Pair with
-                  primal="cg" — a sharded (D, D) Cholesky factor would
-                  defeat the point.
-    """
+def _prepare_fit(config: FitConfig, problem: Problem | None, oracle,
+                 mesh):
+    """fit()'s host work before the first chunk dispatch: admission, the
+    problem and oracle, the runners and their initial carry. -> (carry0,
+    chunk_fn, theta_fn, rff_params)."""
     if isinstance(problem, StreamProblem):
         raise ValueError(
             "fit() drives batch problems; run a StreamProblem through "
@@ -226,11 +212,44 @@ def fit(config: FitConfig, problem: Problem | None = None, *,
     carry0, chunk_fn, theta_fn = _phased_runner(
         make_runner, phase_plan(ctx, config.resolved_iters,
                                 problem.adjacency))
+    return carry0, chunk_fn, theta_fn, rff_params
 
-    carry, history = _chunked_scan(chunk_fn, carry0, config.resolved_iters,
-                                   config.chunk_size, progress_cb)
-    return FitResult(config=config, state=carry, history=history,
-                     theta=theta_fn(carry), rff_params=rff_params)
+
+def fit(config: FitConfig, problem: Problem | None = None, *,
+        progress_cb: ProgressCb | None = None,
+        oracle: jax.Array | None = None,
+        mesh=None) -> FitResult:
+    """Run `config.algorithm` on `config.backend` and record the paper's
+    evaluation trajectories.
+
+    problem     — an existing `admm.Problem`; None builds one from
+                  config.krr / config.graph (see repro.api.build_problem).
+    progress_cb — called as progress_cb(iters_done, last_metrics) after
+                  every `config.chunk_size` iterations.
+    oracle      — theta* (D,) for per-iteration distance-to-oracle; computed
+                  via the closed form when `config.record_oracle_distance`
+                  is set and no oracle is passed.
+    mesh        — optional jax mesh for the big-D path: the problem's
+                  feature dim shards over the mesh's "model" axis and the
+                  agent dim over its batch axes (theta/theta_hat/gamma live
+                  as (N, D/shards) per device; see
+                  distributed.sharding.feature_spec). Pair with
+                  primal="cg" — a sharded (D, D) Cholesky factor would
+                  defeat the point.
+
+    In a `jax.profiler` trace the call is the host span `repro.fit`, its
+    work before the first dispatch `repro.fit.prepare`, and each chunk
+    dispatch `repro.fit.chunk`.
+    """
+    with jax.profiler.TraceAnnotation("repro.fit"):
+        with jax.profiler.TraceAnnotation("repro.fit.prepare"):
+            carry0, chunk_fn, theta_fn, rff_params = _prepare_fit(
+                config, problem, oracle, mesh)
+        carry, history = _chunked_scan(chunk_fn, carry0,
+                                       config.resolved_iters,
+                                       config.chunk_size, progress_cb)
+        return FitResult(config=config, state=carry, history=history,
+                         theta=theta_fn(carry), rff_params=rff_params)
 
 
 def fit_stream(config: FitConfig, stream: StreamProblem | None = None, *,

@@ -207,47 +207,58 @@ class StepProgram:
 def run_step(program: StepProgram, state):
     """Execute one iteration of `program` on a (theta, theta_hat, gamma,
     step, comms, comm) carry; returns (new_state, extras) with extras the
-    primal stage's auxiliary outputs (e.g. the streaming regret sample)."""
+    primal stage's auxiliary outputs (e.g. the streaming regret sample).
+
+    Each stage runs under `jax.named_scope("coke.<stage>")` (exchange,
+    primal, comm_decide, dual, record): compile-time metadata that a
+    profiler trace carries in each device op's name stack, so a stage's
+    device time can be read off the trace. It changes no computation."""
     chain = program.chain
     k = state.step + 1
     comm_state = chain.ensure_state(state.comm, state.theta.shape[0])
-    g = program.exchange(state, k)
-
     theta0, theta_hat0, gamma0 = state.theta, state.theta_hat, state.gamma
-    if g.joined is not None:
-        # a (re)joining agent restarts cold: zero primal/broadcast/dual
-        theta0, theta_hat0, gamma0 = _mask_rows(
-            g.joined, jax.tree.map(jnp.zeros_like, (theta0, theta_hat0,
-                                                    gamma0)),
-            (theta0, theta_hat0, gamma0))
 
-    nbr_hat = (None if program.primal_owns_exchange
-               else g.nbr_sum(theta_hat0))
-    theta_new, extras = program.primal(k, g, theta0, theta_hat0, gamma0,
-                                       nbr_hat)
+    with jax.named_scope("coke.exchange"):
+        g = program.exchange(state, k)
+        if g.joined is not None:
+            # a (re)joining agent restarts cold: zero primal/broadcast/dual
+            theta0, theta_hat0, gamma0 = _mask_rows(
+                g.joined, jax.tree.map(jnp.zeros_like, (theta0, theta_hat0,
+                                                        gamma0)),
+                (theta0, theta_hat0, gamma0))
+        nbr_hat = (None if program.primal_owns_exchange
+                   else g.nbr_sum(theta_hat0))
 
-    if program.comm_decide is not None:
-        # gossip: sleepers hold their primal iterate, are structurally
-        # silent in the broadcast (zero bits), and their duals freeze
-        # (delayed-but-correct — the next wake integrates (21b) against
-        # the then-current broadcast values)
-        m = program.comm_decide(comm_state.key, k, g)
-        theta = _mask_rows(m, theta_new, theta0)
-    else:
-        m = None
-        theta = theta_new
+    with jax.named_scope("coke.primal"):
+        theta_new, extras = program.primal(k, g, theta0, theta_hat0,
+                                           gamma0, nbr_hat)
 
-    theta_hat, send, comm_state = chain.apply(theta, theta_hat0, k,
-                                              comm_state, active=m)
+    with jax.named_scope("coke.comm_decide"):
+        if program.comm_decide is not None:
+            # gossip: sleepers hold their primal iterate, are structurally
+            # silent in the broadcast (zero bits), and their duals freeze
+            # (delayed-but-correct — the next wake integrates (21b)
+            # against the then-current broadcast values)
+            m = program.comm_decide(comm_state.key, k, g)
+            theta = _mask_rows(m, theta_new, theta0)
+        else:
+            m = None
+            theta = theta_new
+        theta_hat, send, comm_state = chain.apply(theta, theta_hat0, k,
+                                                  comm_state, active=m)
 
-    # dual (21b): gamma_i += rho * sum_n (theta_hat_i - theta_hat_n)
-    nbr_new = g.nbr_sum(theta_hat)
-    gamma = gamma0 + program.rho * (g.deg[:, None] * theta_hat - nbr_new)
-    if m is not None:
-        gamma = _mask_rows(m, gamma, gamma0)
+    with jax.named_scope("coke.dual"):
+        # dual (21b): gamma_i += rho * sum_n (theta_hat_i - theta_hat_n)
+        nbr_new = g.nbr_sum(theta_hat)
+        gamma = gamma0 + program.rho * (g.deg[:, None] * theta_hat
+                                        - nbr_new)
+        if m is not None:
+            gamma = _mask_rows(m, gamma, gamma0)
+
+    with jax.named_scope("coke.record"):
+        comms = state.comms + jnp.sum(send.astype(jnp.int32))
 
     new_state = type(state)(
         theta=theta, theta_hat=theta_hat, gamma=gamma, step=k,
-        comms=state.comms + jnp.sum(send.astype(jnp.int32)),
-        comm=comm_state)
+        comms=comms, comm=comm_state)
     return new_state, extras

@@ -82,6 +82,7 @@ def _coke_fused_update(theta, theta_hat, gamma, grad, left, right, *,
 
     gaug, xisq = pl.pallas_call(
         functools.partial(_coke_kernel, rho=rho, deg=deg),
+        name="coke_fused_update",
         grid=(N, nblocks),
         in_specs=[row_spec] * 6,
         out_specs=[
@@ -227,12 +228,16 @@ def _coke_megastep(theta, theta_hat, gamma, phi, y, *, rho, lam, lr,
     # (`None`), so each block's last two dims either span the whole array
     # dim or are (8, 128)-aligned — the tiling rule Mosaic enforces:
     # per-agent rows are (N, 1, Dp), labels (N, Tp, 1), xi_sq (N, 1, 1).
+    # The layout copies run under the `coke.layout` scope, so a profiler
+    # trace tells them from the pallas_call itself.
     pad_row = lambda a: jnp.pad(a.astype(jnp.float32),
                                 ((0, 0), (0, Dp - D)))[:, None, :]
-    theta, theta_hat, gamma = map(pad_row, (theta, theta_hat, gamma))
-    phi = jnp.pad(phi.astype(jnp.float32),
-                  ((0, 0), (0, Tp - T), (0, Dp - D)))
-    y = jnp.pad(y.astype(jnp.float32), ((0, 0), (0, Tp - T)))[:, :, None]
+    with jax.named_scope("coke.layout"):
+        theta, theta_hat, gamma = map(pad_row, (theta, theta_hat, gamma))
+        phi = jnp.pad(phi.astype(jnp.float32),
+                      ((0, 0), (0, Tp - T), (0, Dp - D)))
+        y = jnp.pad(y.astype(jnp.float32),
+                    ((0, 0), (0, Tp - T)))[:, :, None]
 
     row_spec = pl.BlockSpec((None, 1, Dp), lambda i, t: (i, 0, 0))
     nbr_specs = []
@@ -244,6 +249,7 @@ def _coke_megastep(theta, theta_hat, gamma, phi, y, *, rho, lam, lr,
 
     theta_new, xisq = pl.pallas_call(
         functools.partial(_megastep_kernel, n_nbr=n_nbr, nt=nt, **sc),
+        name="coke_megastep",
         grid=(N, nt),
         in_specs=[row_spec, row_spec, row_spec, *nbr_specs,
                   pl.BlockSpec((None, bt, Dp), lambda i, t: (i, t, 0)),
@@ -265,7 +271,8 @@ def _coke_megastep(theta, theta_hat, gamma, phi, y, *, rho, lam, lr,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(theta, theta_hat, gamma, *([theta_hat] * n_nbr), phi, y)
-    return theta_new[:, 0, :D], xisq[:, 0, 0]
+    with jax.named_scope("coke.layout"):
+        return theta_new[:, 0, :D], xisq[:, 0, 0]
 
 
 def coke_megastep(theta: jax.Array, theta_hat: jax.Array, gamma: jax.Array,

@@ -52,6 +52,7 @@ def _rff_pallas(x: jax.Array, omega: jax.Array, bias: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_rff_kernel, scale=scale),
+        name="rff_pallas",
         grid=(Tp // bt, Lp // bl),
         in_specs=[
             pl.BlockSpec((bt, dp), lambda i, j: (i, 0)),
