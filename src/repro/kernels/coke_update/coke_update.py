@@ -20,11 +20,14 @@ via `input_output_aliases`, and block shapes are derived from
 `launch/analysis.py`'s `roofline()` helper (see
 `megastep_launch_params`).
 
-Grid: (N_agents, T_pad / block_t), sample axis innermost. The gradient
-accumulator lives in VMEM scratch; the final sample step applies the
-consensus terms and writes theta_new plus the censor partial sum
-xi_sq = ||theta_new - theta_hat||^2 (zero padding of both T and D
-contributes exactly zero — pinned in tests).
+Grid: (N_agents, T_pad / block_t), sample axis innermost. block_t is
+chosen among the multiples of 8 that divide T, so T_pad == T whenever
+T % 8 == 0 and phi enters the `pallas_call` as the caller holds it; only
+a T with no such divisor (or a D off the 128-lane tile) makes the
+wrapper pad phi. The gradient accumulator lives in VMEM scratch; the
+final sample step applies the consensus terms and writes theta_new plus
+the censor partial sum xi_sq = ||theta_new - theta_hat||^2 (zero
+padding of both T and D contributes exactly zero — pinned in tests).
 
 `interpret` defaults to None = resolve via
 `repro.kernels.runtime.resolve_interpret` (interpret on CPU, compiled
@@ -137,11 +140,17 @@ def megastep_launch_params(n_agents: int, n_samples: int, dim: int,
                            ) -> MegastepLaunch:
     """Derive the sample-block size and padded shapes for the megakernel.
 
-    The feature dim is padded to the 128-lane tile; the sample block is
-    the largest sublane multiple (of 8, capped at 512) whose streamed
-    tiles — double-buffered — fit in `vmem_budget` alongside the
-    VMEM-resident per-agent rows (theta, theta_hat, gamma, the 2k rolled
-    neighbor views, the donated output, and the gradient scratch). The
+    The feature dim is padded to the 128-lane tile. The sample block is
+    capped at the largest sublane multiple (of 8, at most 512 and at
+    most T rounded up to 8) whose streamed tiles — double-buffered — fit
+    in `vmem_budget` alongside the VMEM-resident per-agent rows (theta,
+    theta_hat, gamma, the 2k rolled neighbor views, the donated output,
+    and the gradient scratch). Within that cap it is the largest
+    multiple of 8 that divides T, so the grid tiles T exactly and the
+    wrapper copies no phi: a smaller block costs a few grid steps, a
+    padded T a full read and write of phi on every call. Only a T with
+    no multiple-of-8 divisor (T % 8 != 0) takes the cap itself and is
+    padded up to a multiple of it. An explicit `block_t` wins. The
     resulting cost dict feeds both `pl.CostEstimate` and
     `launch.analysis.roofline` so the launch carries its own
     compute-vs-memory bound.
@@ -155,6 +164,7 @@ def megastep_launch_params(n_agents: int, n_samples: int, dim: int,
                 bt = cand
                 break
         bt = min(bt, ((max(n_samples, 1) + 7) // 8) * 8)
+        bt = next((c for c in range(bt, 7, -8) if n_samples % c == 0), bt)
     else:
         bt = block_t
     Tp = ((max(n_samples, 1) + bt - 1) // bt) * bt
@@ -229,13 +239,16 @@ def _coke_megastep(theta, theta_hat, gamma, phi, y, *, rho, lam, lr,
     # dim or are (8, 128)-aligned — the tiling rule Mosaic enforces:
     # per-agent rows are (N, 1, Dp), labels (N, Tp, 1), xi_sq (N, 1, 1).
     # The layout copies run under the `coke.layout` scope, so a profiler
-    # trace tells them from the pallas_call itself.
+    # trace tells them from the pallas_call itself. phi is the large
+    # operand: it is padded (a full copy) only where the block walk
+    # cannot tile T and D exactly; the cast is free for float32 phi.
     pad_row = lambda a: jnp.pad(a.astype(jnp.float32),
                                 ((0, 0), (0, Dp - D)))[:, None, :]
     with jax.named_scope("coke.layout"):
         theta, theta_hat, gamma = map(pad_row, (theta, theta_hat, gamma))
-        phi = jnp.pad(phi.astype(jnp.float32),
-                      ((0, 0), (0, Tp - T), (0, Dp - D)))
+        phi = phi.astype(jnp.float32)
+        if (Tp, Dp) != (T, D):
+            phi = jnp.pad(phi, ((0, 0), (0, Tp - T), (0, Dp - D)))
         y = jnp.pad(y.astype(jnp.float32),
                     ((0, 0), (0, Tp - T)))[:, :, None]
 

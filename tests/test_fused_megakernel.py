@@ -70,12 +70,21 @@ def test_megastep_bitwise_vs_reference(n, t, d, offsets, bt):
     np.testing.assert_array_equal(np.asarray(xi_k), np.asarray(xi_r))
 
 
-def test_megastep_launch_params_roofline():
-    """Block sizing respects the VMEM budget and the launch carries its
-    own roofline verdict (derived from launch.analysis)."""
-    lp = megastep_launch_params(8, 1000, 4096, 2)
+@pytest.mark.parametrize("n,t,d,block_t,padded_t", [
+    (48, 2048, 16384, 32, 2048),  # the benchmark cell: 56 fits, 32 divides
+    (8, 1000, 4096, 200, 1000),   # 248 fits, 200 divides
+    (8, 1001, 4096, 248, 1240),   # no multiple of 8 divides T: pad
+    (4, 40, 32, 40, 40),          # the whole of T in one block
+], ids=["d16k", "divisor", "no-divisor", "one-block"])
+def test_megastep_launch_params_roofline(n, t, d, block_t, padded_t):
+    """Block sizing respects the VMEM budget, takes the largest
+    multiple-of-8 divisor of T under it so that phi needs no pad, pads
+    only a T that has none, and the launch carries its own roofline
+    verdict (derived from launch.analysis)."""
+    lp = megastep_launch_params(n, t, d, 2)
+    assert (lp.block_t, lp.padded_t) == (block_t, padded_t)
     assert lp.block_t % 8 == 0 and lp.padded_d % 128 == 0
-    assert lp.padded_t % lp.block_t == 0 and lp.padded_t >= 1000
+    assert lp.padded_t % lp.block_t == 0 and lp.padded_t >= t
     streamed = 2 * (lp.block_t * lp.padded_d * 4 + lp.block_t * 4)
     resident = (5 + 2) * lp.padded_d * 4
     assert streamed + resident <= 8 * 1024 * 1024

@@ -11,7 +11,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all
 import this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +66,23 @@ def test_megastep_compiles_for_v5e(f32):
     compiled = step.lower(f32(N, D), f32(N, D), f32(N, D), f32(N, T, D),
                           f32(N, T)).compile()
     assert _kernel_calls(compiled) == 1
+
+
+def test_megastep_copies_no_phi_for_v5e(f32):
+    """At the benchmark cell's shape the sample block divides T, so phi
+    goes into the kernel as it is: no pad of phi in the compiled program
+    and no temp buffer of its size (a padded copy would take 6.58 GB)."""
+    N, T, D = 48, 2048, 16384
+    step = jax.jit(lambda th, hat, gm, phi, y: coke_megastep(
+        th, hat, gm, phi, y, rho=1e-2, lam=5e-5, lr=0.1, interpret=False))
+    compiled = step.lower(f32(N, D), f32(N, D), f32(N, D), f32(N, T, D),
+                          f32(N, T)).compile()
+    assert _kernel_calls(compiled) == 1
+    phi_bytes = N * T * D * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < phi_bytes / 100
+    padded = [math.prod(map(int, dims.split(","))) for dims in re.findall(
+        r"= f32\[([\d,]+)\]\S* pad\(", compiled.as_text())]
+    assert all(size < N * T * D for size in padded), padded
 
 
 def test_fused_update_compiles_for_v5e(f32):
