@@ -29,12 +29,13 @@ def fit_readings(cell, seed: int, devices, program: bool) -> dict:
 
     from chipbench import fit_cell
     config, iters = cell.config, int(cell.traffic["num_iters"])
-    cfg, problem, (phi, labels) = fit_cell.build(config, seed, devices)
+    cfg, problem, (phi, labels), mesh = fit_cell.build(config, seed,
+                                                       devices)
     out = {}
     if program:
         from repro.api import fit
         res = harness.ready(fit(cfg.replace(num_iters=iters),
-                                problem=problem))
+                                problem=problem, mesh=mesh))
         ans = (np.asarray(res.theta), np.asarray(res.history["train_mse"]),
                np.asarray(res.history["comms"]))
         del res
@@ -57,6 +58,7 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     sys.path.insert(0, os.path.join(spec.ROOT, "src"))
     cell = spec.resolve(args.workload)
+    harness.runner(cell)
     devices = harness.device_check(cell.chips)
     harness.enable_cache()
     on = f"{devices[0].device_kind} x{len(devices)}"

@@ -66,21 +66,24 @@ def rff_draw(seed: int, input_dim: int, dim: int, bandwidth: float):
 
 
 @functools.lru_cache(maxsize=None)
-def _features_fn():
+def _features_fn(sharding=None):
     import jax
     import jax.numpy as jnp
 
-    @jax.jit
     def feats(x, omega, bias):
         dim = omega.shape[1]
         proj = jnp.einsum("ntd,dl->ntl", x, omega,
                           precision=jax.lax.Precision.HIGHEST)
         return (np.sqrt(2.0 / dim).astype(np.float32)
                 * jnp.cos(proj + bias))
-    return feats
+    if sharding is None:
+        return jax.jit(feats)
+    return jax.jit(feats, out_shardings=sharding)
 
 
-def features(x, omega, bias):
+def features(x, omega, bias, sharding=None):
     """phi(x) = sqrt(2/D) cos(x omega + b) for (N, T, d) inputs, at full
-    float32, in one jitted call on the device."""
-    return _features_fn()(x, omega, bias)
+    float32, in one jitted call on the device. With a `sharding`, phi is
+    made straight into it: each device computes its own slice and no
+    device ever holds the whole."""
+    return _features_fn(sharding)(x, omega, bias)

@@ -180,7 +180,7 @@ def _metric_line(run: Run, metrics) -> dict:
 
 
 def result_line(run: Run, devices, traced: bool) -> dict:
-    from chipbench import trace
+    from chipbench import stages, trace
     cell = run.cell
     metrics = _metric_line(run, cell.per_layer if traced
                            else cell.end_to_end)
@@ -194,8 +194,11 @@ def result_line(run: Run, devices, traced: bool) -> dict:
         device["busy_s"] = (sum(busy.values()) / len(busy) / 1e9
                             if busy else 0.0)
         device["window_s"] = trace.window_ns(run.trace) / 1e9
-        line["breakdown"] = {"device_ops": trace.top_ops(run.trace),
-                             "idle_gaps": trace.idle_gaps(run.trace)}
+        # device ops as "<stage> <op>", idle gaps by the innermost
+        # benchmark or program span open in each
+        line["breakdown"] = {
+            "device_ops": trace.top_ops(stages.record(run)),
+            "idle_gaps": trace.idle_gaps(run.trace)}
     line["checks"] = {k: {"value": v, "limit": lim}
                       for k, (v, lim) in run.checks.items()}
     return line
@@ -211,13 +214,20 @@ def parse(argv=None):
     return p.parse_args(argv)
 
 
-def drive(cell, args, t0: float, devices, *, on: str, peaks) -> Run:
-    """Run the cell with the runner of its traffic kind ("fit")."""
+def runner(cell):
+    """The runner of the cell's traffic kind ("fit"), once it has checked
+    the cell's configuration: raises Refused before any set-up."""
     from chipbench import fit_cell
+    fit_cell.check(cell)
+    return fit_cell
+
+
+def drive(cell, args, t0: float, devices, *, on: str, peaks) -> Run:
+    """Run the cell with the runner of its traffic kind."""
     run = Run(cell=cell, seed=args.seed, seconds=args.seconds, on=on,
               peaks=peaks)
-    fit_cell.run(run, devices, t0=t0, tracer=Tracer(cell.name,
-                                                    bool(args.trace)))
+    runner(cell).run(run, devices, t0=t0,
+                     tracer=Tracer(cell.name, bool(args.trace)))
     return run
 
 
@@ -228,6 +238,7 @@ def main(argv=None, t0: float | None = None) -> int:
     try:
         cell = spec.resolve(args.workload)
         traffic.check(cell.traffic)
+        runner(cell)
         devices = device_check(cell.chips)
     except (Refused, KeyError, ValueError, FileNotFoundError,
             ImportError) as e:

@@ -7,11 +7,10 @@ primal, comm_decide, dual, record; the megakernel wrapper's layout copies,
 `coke.layout`; the per-iteration history, `coke.history`), and spans
 `fit()`'s host work with `jax.profiler.TraceAnnotation`s (`repro.fit`,
 `repro.fit.prepare`, `repro.fit.chunk`). `load` reads the cell's
-`.xplane.pb` once more and keeps, of each device op, the stage its name
-stack puts it in: the innermost `coke.*` component, "unscoped" where there
-is none. The record has the shape `trace.load` gives, with each op's name
-replaced by its stage, and the host spans of the program beside the
-harness's, so `trace.py`'s reductions apply to it unchanged
+`.xplane.pb` once more and names each device op by the stage its name
+stack puts it in, the innermost `coke.*` component ("unscoped" where there
+is none), and by its op: "<stage> <op>". The record has the shape
+`trace.load` gives, so `trace.py`'s reductions apply to it unchanged
 (`tests/test_chipbench_stages.py`).
 """
 from __future__ import annotations
@@ -29,7 +28,6 @@ UNSCOPED = "unscoped"
 # HLO `op_name`, as "<stack>:"). `jax.profiler.ProfileData` shows no
 # metadata stats, so the device planes are decoded from the file here.
 NAME_STACK_STAT = "tf_op"
-HOST_PREFIXES = ("repro.", trace.SPAN_PREFIX)
 FIT_SPAN = "repro.fit"
 CONSENSUS = ("coke.exchange", "coke.comm_decide", "coke.dual",
              "coke.record")
@@ -42,6 +40,11 @@ def stage_of(name_stack: str) -> str:
         if part.startswith(SCOPE_PREFIX):
             return part
     return UNSCOPED
+
+
+def stage_name(op: str) -> str:
+    """The stage of a record's device op, named "<stage> <op>"."""
+    return op.split(" ", 1)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -89,8 +92,8 @@ def _text(value) -> str:
 
 
 def _device_ops(plane) -> list:
-    """[[stage, start_ns, dur_ns], ...] of a device plane's `XLA Ops`
-    line, control flow left out as `trace.load` leaves it out."""
+    """[["<stage> <op>", start_ns, dur_ns], ...] of a device plane's `XLA
+    Ops` line, control flow left out as `trace.load` leaves it out."""
     lines, event_meta, stat_names = [], {}, {}
     for field, value in _fields(plane):
         if field == 3:
@@ -102,7 +105,7 @@ def _device_ops(plane) -> list:
             else:
                 stat_names[entry.get(1, 0)] = _text(
                     dict(_fields(entry.get(2, b""))).get(2, b""))
-    stage = {}
+    named = {}
     for key, meta in event_meta.items():
         name, stack = "", ""
         for field, value in _fields(meta):
@@ -114,7 +117,8 @@ def _device_ops(plane) -> list:
                     stack = (_text(stat[5]) if 5 in stat
                              else stat_names.get(stat.get(7), ""))
         if not name.startswith(trace.CONTROL_FLOW):
-            stage[key] = stage_of(stack)
+            # "%name = shape op(operands)": keep the name, as trace.load
+            named[key] = f"{stage_of(stack)} {name.split(' = ')[0]}"
     ops = []
     for line in lines:
         head = {k: v for k, v in _fields(line) if k in (2, 3)}
@@ -124,16 +128,16 @@ def _device_ops(plane) -> list:
         for field, value in _fields(line):
             if field == 4:
                 e = dict(_fields(value))
-                if e.get(1) in stage:  # whole ns, as ProfileData gives
-                    ops.append([stage[e[1]], t0 + e.get(2, 0) // 1000,
+                if e.get(1) in named:  # whole ns, as ProfileData gives
+                    ops.append([named[e[1]], t0 + e.get(2, 0) // 1000,
                                 float(e.get(3, 0) // 1000)])
     return ops
 
 
 def load(path: str) -> dict:
-    """Read an xplane file into {"devices": {name: [[stage, start, dur],
-    ...]}, "host": [[span, start, dur], ...], "window": [start, end]} (ns),
-    once per file."""
+    """Read an xplane file into {"devices": {name: [["<stage> <op>",
+    start, dur], ...]}, "host": [[span, start, dur], ...], "window":
+    [start, end]} (ns), once per file."""
     return _load(path, os.stat(path).st_mtime_ns)
 
 
@@ -158,7 +162,7 @@ def _load(path: str, _mtime_ns: int) -> dict:
             for plane in ProfileData.from_file(path).planes
             if plane.name.startswith("/host:CPU")
             for line in plane.lines for e in line.events
-            if e.name.startswith(HOST_PREFIXES)]
+            if e.name.startswith(trace.SPAN_PREFIX)]
     windows = [h for h in host if h[0] == trace.WINDOW_SPAN]
     if not windows:
         raise ValueError(f"{path} has no {trace.WINDOW_SPAN} span")
@@ -203,9 +207,11 @@ class Split:
 def split(record: dict, iterations: int) -> Split:
     """Reduce a `load` record with `trace.py`'s interval functions."""
     n_dev = len(record["devices"]) or 1
-    stages = {s for ops in record["devices"].values() for s, _, _ in ops}
-    stage_ns = {s: sum(trace.op_ns(record, lambda n, s=s: n == s).values())
-                / n_dev for s in stages}
+    stages = {stage_name(op) for ops in record["devices"].values()
+              for op, _, _ in ops}
+    stage_ns = {s: sum(trace.op_ns(
+        record, lambda op, s=s: stage_name(op) == s).values()) / n_dev
+        for s in stages}
     spans = {h[0] for h in record["host"]}
     idle_ns = {span: s * 1e9 / n_dev
                for span, s in trace.idle_gaps(record, k=len(spans) + 1)}
@@ -218,17 +224,28 @@ def split(record: dict, iterations: int) -> Split:
                  idle_ns=idle_ns)
 
 
-def read(run) -> Split | None:
-    """The split of a traced fit run, logged to standard error per
-    iteration; None where the run was not traced."""
-    if run.trace is None or run.fit is None or not run.fit["iterations"]:
+def record(run) -> dict | None:
+    """The `load` record of a traced run; None where the run was not
+    traced or left no trace file."""
+    if run.trace is None:
         return None
     try:
         path = trace.find_xplane(os.path.join(harness.OUT, "trace",
                                               run.cell.name))
     except FileNotFoundError:
         return None
-    sp = split(load(path), run.fit["iterations"])
+    return load(path)
+
+
+def read(run) -> Split | None:
+    """The split of a traced fit run, logged to standard error per
+    iteration; None where the run was not traced."""
+    if run.fit is None or not run.fit["iterations"]:
+        return None
+    rec = record(run)
+    if rec is None:
+        return None
+    sp = split(rec, run.fit["iterations"])
     per_iter = lambda ns: ns / sp.iterations / 1e6  # noqa: E731
     parts = ", ".join(f"{s} {per_iter(ns)!r}" for s, ns in
                       sorted(sp.stage_ns.items(), key=lambda kv: -kv[1]))

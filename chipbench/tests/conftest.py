@@ -19,6 +19,15 @@ def tiny_cell(name: str):
                                traffic=dict(cell.traffic, num_iters=20))
 
 
+def tiny_sharded_cell():
+    """d16k.fit's tiny cell as a feature-sharded fit over four devices
+    with the exact primal (CG) on the spmd backend."""
+    cell = tiny_cell("d16k.fit")
+    config = dict(cell.config, mesh={"data": 1, "model": 4},
+                  fit=dict(cell.config["fit"], backend="spmd", primal="cg"))
+    return dataclasses.replace(cell, chips=4, config=config)
+
+
 class Args:
     def __init__(self, seed=2**31 + 17, seconds=0.5, trace=0):
         self.seed, self.seconds, self.trace = seed, seconds, trace

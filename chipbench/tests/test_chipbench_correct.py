@@ -34,8 +34,8 @@ def test_fit_run_is_correct():
 def test_fit_control_fails():
     cell = tiny_cell("d16k.fit")
     config, iters = cell.config, cell.traffic["num_iters"]
-    _, problem, (phi, labels) = fit_cell.build(config, SEED,
-                                               jax.devices()[:1])
+    _, problem, (phi, labels), _ = fit_cell.build(config, SEED,
+                                                  jax.devices()[:1])
     ref = fit_cell.reference_fit(config, phi, labels, iters,
                                  reference.REFERENCE)
     ctl = fit_cell.reference_fit(config, phi, labels, iters,

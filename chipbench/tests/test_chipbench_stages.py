@@ -210,14 +210,17 @@ def test_the_name_stack_stat_is_in_the_tpu_plane():
 
 def test_stages_load_matches_trace_load():
     """`stages.load` gives the same device intervals as `trace.load`, each
-    under its stage, and the program's host spans nested in the
-    harness's."""
+    named "<stage> <op>" by its stage and the op `trace.load` names, and
+    the same host spans, the program's nested in the harness's."""
     rec, st = trace.load(DATA), stages.load(DATA)
     assert st["window"] == rec["window"]
+    assert st["host"] == rec["host"]
     ops, staged = rec["devices"]["TPU:0"], st["devices"]["TPU:0"]
     assert [o[1:] for o in staged] == [o[1:] for o in ops]
     by_name = {}
-    for (name, _, _), (stage, _, _) in zip(ops, staged):
+    for (name, _, _), (named, _, _) in zip(ops, staged):
+        stage = stages.stage_name(named)
+        assert named == f"{stage} {name}"
         by_name.setdefault(name, set()).add(stage)
     assert {s for n, ss in by_name.items() if _is_kernel(n)
             for s in ss} == {"coke.primal"}
@@ -243,3 +246,27 @@ def test_split_of_the_recorded_trace():
     assert sp.stage_ms("coke.primal") > 0 and sp.stage_ms("coke.layout") > 0
     assert set(sp.idle_ns) <= {"chipbench.fit", "repro.fit",
                                "repro.fit.prepare", "repro.fit.chunk"}
+
+
+def test_breakdown_names_stages_and_program_spans(monkeypatch):
+    """A traced run's `breakdown` names each device op by its stage and
+    op, and each idle gap by the innermost span open in it, the
+    program's `repro.*` spans among them."""
+    from chipbench import harness
+    monkeypatch.setattr(trace, "find_xplane", lambda d: DATA)
+    run = harness.Run(cell=types.SimpleNamespace(name="d16k.fit",
+                                                 end_to_end=(),
+                                                 per_layer=()),
+                      seed=0, seconds=1.0, on="test", peaks=None,
+                      trace=trace.load(DATA))
+    device = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    b = harness.result_line(run, [device], traced=True)["breakdown"]
+    ops = dict(b["device_ops"])
+    assert len(ops) == 10
+    assert "coke.primal %coke_megastep.8" in ops
+    assert all(stages.stage_name(op).startswith(("coke.", stages.UNSCOPED))
+               and op.split(" ", 1)[1].startswith("%") for op in ops)
+    gaps = dict(b["idle_gaps"])
+    assert "repro.fit.prepare" in gaps
+    assert set(gaps) <= {"chipbench.fit", "repro.fit", "repro.fit.prepare",
+                         "repro.fit.chunk"}

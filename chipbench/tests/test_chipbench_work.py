@@ -35,3 +35,20 @@ def test_v5e_peaks():
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError, match="no published peaks"):
         peaks.peaks_for("TPU v99")
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_fit_step_mfu_counts_every_chip(chips):
+    """The whole step's share is of the peaks of all the cell's chips: the
+    same work in the same time on four chips reads a quarter."""
+    import types
+
+    from chipbench import spec
+    read = spec.load_reader("fit_step_mfu")
+    work_ = work.admm_iteration(32, 2048, 65536)
+    p = peaks.peaks_for("TPU v5 lite")
+    run = types.SimpleNamespace(
+        fit={"iterations": 100, "work": work_}, trace={}, window_s=2.0,
+        peaks=p, cell=types.SimpleNamespace(chips=chips))
+    need = work_[1] / 819e9                      # bandwidth-bound
+    assert read(run) == pytest.approx(100.0 * need / 0.02 / chips)

@@ -2,10 +2,10 @@
 
 `load` reads the `.xplane.pb` that `jax.profiler` writes into a plain
 record: per device, its operations as (name, start_ns, dur_ns); on the
-host, the benchmark's own spans (`chipbench.*` TraceAnnotations); and the
-window, the span of `chipbench.window`. Everything else here reduces that
-record, so the reduction can be checked on hand-made records with known
-answers (`tests/test_chipbench_trace.py`).
+host, the benchmark's own spans (`chipbench.*` TraceAnnotations) and the
+program's (`repro.*`); and the window, the span of `chipbench.window`.
+Everything else here reduces that record, so the reduction can be checked
+on hand-made records with known answers (`tests/test_chipbench_trace.py`).
 """
 from __future__ import annotations
 
@@ -14,7 +14,7 @@ import glob
 import json
 import os
 
-SPAN_PREFIX = "chipbench."
+SPAN_PREFIX = ("chipbench.", "repro.")   # the benchmark's, the program's
 WINDOW_SPAN = "chipbench.window"
 # the line of a TPU plane that holds operations (XLA Ops), not their
 # enclosing modules or steps; on it, control flow (a scan's `while`)
@@ -184,7 +184,7 @@ def top_ops(record: dict, k: int = 10) -> list:
 
 
 def _open_span(host, starts, longest: float, t0: float, t1: float) -> str:
-    """The benchmark span that covers the most of [t0, t1], the shorter
+    """The host span that covers the most of [t0, t1], the shorter
     one on a tie; "outside spans" where none does. `host` is sorted by
     start, `starts` its starts, `longest` its longest duration."""
     best, best_cover, best_len = "outside spans", 0.0, float("inf")
@@ -200,7 +200,7 @@ def _open_span(host, starts, longest: float, t0: float, t1: float) -> str:
 
 def idle_gaps(record: dict, k: int = 10) -> list:
     """[[host span, seconds]]: the window's device idle time (summed over
-    devices) grouped by the benchmark span that was open during each gap,
+    devices) grouped by the host span that was open during each gap,
     most idle first."""
     lo, hi = record["window"]
     host = sorted((h for h in record["host"] if h[0] != WINDOW_SPAN
