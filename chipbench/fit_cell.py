@@ -12,9 +12,17 @@ mesh of its cell's a x b chips: the training features are built straight
 into the program's feature sharding (agents over "data", features over
 "model") and every `fit()` gets the mesh. Without it the fit runs on one
 chip.
+
+A `"primal": "cg"` configuration may state the CG primal's stop,
+`"cg_tol"` and `"cg_maxiter"`, which `FitConfig` gets as stated; where it
+states none, `FitConfig`'s defaults hold. `"cg_tol": 0` with
+`"cg_maxiter": K` runs exactly K CG steps in every ADMM iteration, on
+every seed. A `"fit"` key that neither the run nor the reference reads
+is refused, as is any graph but the ring, which both build.
 """
 from __future__ import annotations
 
+import math
 import time
 
 import numpy as np
@@ -26,18 +34,44 @@ MESH_AXES = ("data", "model")   # the axes the program's sharding rules use
 REFERENCES = {"gradient": reference.coke_gradient_fit,
               "cg": reference.coke_exact_fit,
               "cholesky": reference.coke_exact_fit}
+# the CG primal's stop, as `FitConfig` takes it
+CG_STOP = {"cg_tol": float, "cg_maxiter": int}
+# every key of a configuration's "fit" that `build` or `reference_fit` reads
+FIT_KEYS = {"algorithm", "backend", "primal", "graph", "lam", "rho",
+            "censor_v", "censor_mu", "inner_lr", *CG_STOP}
 
 
 def check(cell) -> None:
     """Refuse, before any set-up, a configuration the run could not
     measure or could not compare: a mesh that is not the cell's chips or
-    does not divide the agents and features, a primal with no
-    reference."""
+    does not divide the agents and features, a primal with no reference,
+    a fit key the run would ignore, a graph other than the ring, a CG
+    stop for another primal or one that is not a stop."""
     config = cell.config
-    primal = config["fit"]["primal"]
+    f = config["fit"]
+    primal = f["primal"]
     if primal not in REFERENCES:
         raise harness.Refused(f"no reference for primal={primal!r} (known: "
                               f"{sorted(REFERENCES)})")
+    unknown = sorted(set(f) - FIT_KEYS)
+    if unknown:
+        raise harness.Refused(f"fit keys {unknown} are read by nothing "
+                              f"(known: {sorted(FIT_KEYS)})")
+    if f.get("graph", "ring") != "ring":
+        raise harness.Refused(f"graph={f['graph']!r}: the run and its "
+                              f"references build a ring")
+    for key in CG_STOP:
+        if key in f and primal != "cg":
+            raise harness.Refused(f"{key} is the CG primal's stop, and "
+                                  f"primal={primal!r}")
+    maxiter = f.get("cg_maxiter", 1)
+    if type(maxiter) is not int or maxiter < 1:
+        raise harness.Refused(f"cg_maxiter={maxiter!r} is not an integer "
+                              f"of at least 1")
+    tol = f.get("cg_tol", 0.0)
+    if type(tol) not in (int, float) or not 0 <= tol < math.inf:
+        raise harness.Refused(f"cg_tol={tol!r} is not a finite number of "
+                              f"at least 0")
     mesh = config.get("mesh", {"data": 1, "model": 1})
     if sorted(mesh) != sorted(MESH_AXES):
         raise harness.Refused(f"mesh {mesh} names other axes than "
@@ -87,6 +121,7 @@ def build(config: dict, seed: int, devices):
             sharding=NamedSharding(mesh, P("data", None, "model")))
         labels = put(y, "data", None)
     problem = make_problem(phi, labels, ring(N), lam=f["lam"], rho=f["rho"])
+    stop = {k: cast(f[k]) for k, cast in CG_STOP.items() if k in f}
     cfg = FitConfig(
         algorithm=f["algorithm"], backend=f["backend"], primal=f["primal"],
         graph="ring", num_iters=None, inner_lr=f["inner_lr"],
@@ -94,7 +129,7 @@ def build(config: dict, seed: int, devices):
                       bandwidth=config["bandwidth"], lam=f["lam"],
                       rho=f["rho"], censor_v=f["censor_v"],
                       censor_mu=f["censor_mu"], seed=seed,
-                      mapping=config["mapping"]))
+                      mapping=config["mapping"]), **stop)
     return cfg, problem, (phi, labels), mesh
 
 
@@ -136,7 +171,9 @@ def run(r: harness.Run, devices, *, t0: float, tracer) -> None:
     harness.ready(fit(cfg, problem=problem, mesh=mesh))   # compiles
     answers, durations, res = [], [], None
     r.setup_s = time.monotonic() - t0
-    harness.log(f"[{r.cell.name}] set-up {r.setup_s:.3f} s on {r.on}")
+    stop = (f"; CG stop tol {cfg.cg_tol!r}, maxiter {cfg.cg_maxiter}"
+            if cfg.primal == "cg" else "")
+    harness.log(f"[{r.cell.name}] set-up {r.setup_s:.3f} s on {r.on}{stop}")
     tracer.start()
     with harness.CompileCounter() as counter, \
             harness.span("window", tracer.on):

@@ -1,8 +1,9 @@
 """A fit cell whose configuration states a mesh: its features are built
 straight into the feature sharding and every `fit()` gets the mesh; the
-exact-primal reference it is compared with; and the configurations that
-are refused before any set-up. At a tiny size on the CPU, the four chips
-as four virtual devices in a process of their own."""
+exact-primal reference it is compared with; the CG stop a configuration
+states; and the configurations that are refused before any set-up. At a
+tiny size on the CPU, the four chips as four virtual devices in a
+process of their own."""
 import dataclasses
 import json
 import os
@@ -94,6 +95,79 @@ def test_sharded_cg_cell_on_four_devices():
     assert "no reference for primal='auto'" in out["refused"]
 
 
+# Runs the four-device CG cell in a process that sees four CPU devices:
+# at a CG stop too short to converge, at one that converges, and with the
+# primal's partial sums over D kept on each device. Prints one JSON line.
+FOUR_DEVICE_VARIANTS = """
+import dataclasses, json, sys
+import jax
+from jax.sharding import PartitionSpec as P
+from chipbench import fit_cell
+from chipbench.tests.conftest import drive, tiny_sharded_cell
+from repro.core import admm
+
+seed = int(sys.argv[1])
+cell = tiny_sharded_cell()
+
+
+def stop(cg_maxiter):
+    fit = dict(cell.config["fit"], cg_tol=0, cg_maxiter=cg_maxiter)
+    return dataclasses.replace(cell, config=dict(cell.config, fit=fit))
+
+
+out = {}
+for name, k in (("under_solve", 1), ("converged", 16)):
+    out[name] = drive(stop(k), seed=seed)[1]["correct"]
+
+# each device solves on its own slice of D: every contraction over D in
+# the primal (the matvec's phi @ v, CG's dot products) is that device's
+# partial sum, never summed across the chips
+mesh = fit_cell.build(cell.config, seed, jax.devices()[:4])[3]
+real = admm._primal_cg
+
+
+def no_exchange(problem, gamma, theta_ref, nbr_sum, deg=None, theta0=None,
+                **kw):
+    def local(feats, labels, g, t, nb, d, t0):
+        p = dataclasses.replace(problem, feats=feats, labels=labels)
+        return real(p, g, t, nb, d, theta0=t0, **kw)
+    d = P(None, "model")
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(None, None, "model"), P(), d, d, d,
+                                    P(), d),
+        out_specs=d, check_vma=False)(problem.feats, problem.labels, gamma,
+                                      theta_ref, nbr_sum, deg, theta0)
+
+
+jax.clear_caches()   # the fault lives in traced Python
+admm._primal_cg = no_exchange
+out["no_exchange"] = drive(cell, seed=seed)[1]["correct"]
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_device_variants():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", FOUR_DEVICE_VARIANTS,
+                        str(SEED)], cwd=spec.ROOT, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-4000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("variant,correct", [
+    ("under_solve", False), ("converged", True), ("no_exchange", False)])
+def test_four_device_cg_cell_sees_the_solve(four_device_variants, variant,
+                                            correct):
+    """On the four-device CG cell, `correct` follows the primal's solve:
+    one CG step per ADMM iteration (`cg_tol: 0, cg_maxiter: 1`) is not
+    correct, sixteen are; and a primal whose partial sums over D stay on
+    each device, the exchange between the chips left out, is not."""
+    assert four_device_variants[variant] is correct, four_device_variants
+
+
 def _exact_config():
     """The tiny sharded cell's configuration on one device."""
     config = dict(tiny_sharded_cell().config)
@@ -174,6 +248,60 @@ def test_exact_primal_fault_is_not_correct(monkeypatch, fault):
     assert not line["correct"], (fault, line["checks"])
 
 
+@pytest.mark.parametrize("stop,want", [
+    ({}, (1e-8, 64)),
+    ({"cg_tol": 0, "cg_maxiter": 3}, (0.0, 3)),
+    ({"cg_tol": 1e-5}, (1e-5, 64)),
+], ids=["defaults", "fixed-steps", "tol-only"])
+def test_cg_stop_reaches_the_primal(monkeypatch, capsys, stop, want):
+    """A `cg` configuration's `cg_tol`/`cg_maxiter` are what `FitConfig`
+    holds and what every call of the program's CG primal is traced with;
+    where the configuration states none, `FitConfig`'s defaults. The
+    set-up line names the stop."""
+    from repro.core import admm
+    real, seen = admm._primal_cg, set()
+
+    def spy(*args, tol, maxiter, **kw):
+        seen.add((tol, maxiter))
+        return real(*args, tol=tol, maxiter=maxiter, **kw)
+
+    config = _exact_config()
+    config["fit"] = dict(config["fit"], **stop)
+    cfg = fit_cell.build(config, SEED, jax.devices()[:1])[0]
+    assert (cfg.cg_tol, cfg.cg_maxiter) == want
+    assert type(cfg.cg_tol) is float and type(cfg.cg_maxiter) is int
+    jax.clear_caches()   # the spy lives in traced Python
+    monkeypatch.setattr(admm, "_primal_cg", spy)
+    cell = dataclasses.replace(tiny_sharded_cell(), chips=1, config=config)
+    try:
+        drive(cell, seed=SEED)
+    finally:
+        jax.clear_caches()
+    assert seen == {want}
+    assert f"CG stop tol {want[0]!r}, maxiter {want[1]}" in \
+        capsys.readouterr().err
+
+
+def test_d16k_fit_config_unchanged():
+    """`krr-d16k.json`, which states no CG stop, builds field for field
+    the `FitConfig` it built before the stop could be stated."""
+    from repro.api import FitConfig, KRRConfig
+    config = tiny_cell("d16k.fit").config
+    f = config["fit"]
+    N, T = config["num_agents"], config["samples_per_agent"]
+    want = FitConfig(
+        algorithm=f["algorithm"], backend=f["backend"], primal=f["primal"],
+        graph="ring", num_iters=None, inner_lr=f["inner_lr"],
+        krr=KRRConfig(num_agents=N, samples_per_agent=T,
+                      num_features=config["num_features"],
+                      bandwidth=config["bandwidth"], lam=f["lam"],
+                      rho=f["rho"], censor_v=f["censor_v"],
+                      censor_mu=f["censor_mu"], seed=SEED,
+                      mapping=config["mapping"]))
+    cfg = fit_cell.build(config, SEED, jax.devices()[:1])[0]
+    assert cfg == want
+
+
 def test_build_without_mesh_is_one_device_features():
     """Without a mesh the features are `data.features` on the run's one
     device, bit for bit, and no mesh is handed on."""
@@ -192,10 +320,10 @@ def test_build_without_mesh_is_one_device_features():
     assert np.array_equal(np.asarray(labels), y)
 
 
-def _variant(chips=1, primal="gradient", **config):
+def _variant(chips=1, primal="gradient", fit=None, **config):
     cell = tiny_cell("d16k.fit")
-    config = dict(cell.config, fit=dict(cell.config["fit"], primal=primal),
-                  **config)
+    config = dict(cell.config, fit=dict(cell.config["fit"], primal=primal,
+                                        **(fit or {})), **config)
     return dataclasses.replace(cell, chips=chips, config=config)
 
 
@@ -207,8 +335,18 @@ def _variant(chips=1, primal="gradient", **config):
     (_variant(chips=4, mesh={"data": 1, "tensor": 4}), "other axes"),
     (_variant(chips=4, mesh={"data": 4, "model": 1}, num_agents=6),
      "does not divide"),
+    (_variant(fit={"inner_steps": 5}),
+     "fit keys ['inner_steps'] are read by nothing"),
+    (_variant(fit={"cg_maxiter": 8}),
+     "cg_maxiter is the CG primal's stop, and primal='gradient'"),
+    (_variant(fit={"graph": "erdos_renyi"}), "graph='erdos_renyi'"),
+    (_variant(primal="cg", fit={"cg_maxiter": 0}),
+     "cg_maxiter=0 is not an integer of at least 1"),
+    (_variant(primal="cg", fit={"cg_tol": -1e-8}),
+     "cg_tol=-1e-08 is not a finite number of at least 0"),
 ], ids=["mesh-not-chips", "chips-without-mesh", "no-reference",
-        "unknown-axis", "agents-not-divided"])
+        "unknown-axis", "agents-not-divided", "unknown-fit-key",
+        "stop-without-cg", "not-a-ring", "maxiter-zero", "tol-negative"])
 def test_refused_before_set_up(monkeypatch, capsys, cell, why):
     """The run exits 2 with the reason and prints no result, before it
     looks for a chip, builds a feature or compiles anything."""
